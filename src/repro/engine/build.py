@@ -16,6 +16,13 @@ train_step anatomy (paper Fig. 3 + §4):
      per the optimizer kind), RVH backend when span == dp;
   4. apply the combined delta; optimizer state is ZeRO-1-sharded.
 
+Named scopes put the step's parts into every XLA op's `op_name`, so a
+device trace can be summed by them: `accumulate` is the microbatch
+gradient sum, `optimizer` every optimizer update and application of a
+delta (at span > 1 the synchronous path's combine runs inside
+`dopt.update`, so inside it too). The model's own layers carry
+`attention` / `mamba` / `mlp` (models/transformer.py).
+
 `local_steps > 1` reproduces §5.2 (TensorFlow ResNet-50 on slow TCP):
 each lane performs k *local* optimizer steps and the combined quantity is
 the model delta since the last sync.
@@ -324,10 +331,12 @@ def build_runtime(model: Model, mesh: jax.sharding.Mesh, rpol: RunPolicy,
 
             def body(acc, mb):
                 (l, m), g = grad_fn(params, mb)
-                acc = jax.tree.map(
-                    lambda a, gg: (a.astype(jnp.float32)
-                                   + gg.astype(jnp.float32)).astype(acc_dt),
-                    acc, g)
+                with jax.named_scope("accumulate"):
+                    acc = jax.tree.map(
+                        lambda a, gg: (a.astype(jnp.float32)
+                                       + gg.astype(jnp.float32)
+                                       ).astype(acc_dt),
+                        acc, g)
                 return acc, (l, m)
 
             zeros = jax.tree.map(
@@ -348,13 +357,14 @@ def build_runtime(model: Model, mesh: jax.sharding.Mesh, rpol: RunPolicy,
         lanes = split_lanes(batch)
         (losses, mets), G = lane_grads(params, lanes)
         G = jax.lax.with_sharding_constraint(G, to_shardings(gspecs))
-        if scombiner is not None:
-            delta, opt_state, stats = dopt.update_stats(
-                G, state["opt"], params, scombiner)
-        else:
-            delta, opt_state = dopt.update(G, state["opt"], params)
-            stats = None
-        new_params = dopt.apply(params, delta)
+        with jax.named_scope("optimizer"):
+            if scombiner is not None:
+                delta, opt_state, stats = dopt.update_stats(
+                    G, state["opt"], params, scombiner)
+            else:
+                delta, opt_state = dopt.update(G, state["opt"], params)
+                stats = None
+            new_params = dopt.apply(params, delta)
         metrics = {k: jnp.mean(v) for k, v in mets.items()}
         metrics["grad_lanes"] = jnp.asarray(span, jnp.int32)
         if stats is not None:
@@ -384,9 +394,10 @@ def build_runtime(model: Model, mesh: jax.sharding.Mesh, rpol: RunPolicy,
             def body(carry, mb):
                 p, oi, step = carry
                 (_, mets), g = grad_fn(p, mb)
-                d, oi = dopt.opt.update(g, oi, p, step)
-                p = jax.tree.map(lambda a, b: (a.astype(jnp.float32)
-                                               + b).astype(a.dtype), p, d)
+                with jax.named_scope("optimizer"):
+                    d, oi = dopt.opt.update(g, oi, p, step)
+                    p = jax.tree.map(lambda a, b: (a.astype(jnp.float32)
+                                                   + b).astype(a.dtype), p, d)
                 return (p, oi, step + 1), mets
             (p_end, oi, _), mets = jax.lax.scan(
                 body, (params, opt_inner, opt_state["step"]), lane_batch)
@@ -418,7 +429,8 @@ def build_runtime(model: Model, mesh: jax.sharding.Mesh, rpol: RunPolicy,
             metrics.update(stat_metrics(stats, batch))
         else:
             delta = combiner(deltas)
-        new_params = dopt.apply(state["params"], delta)
+        with jax.named_scope("optimizer"):
+            new_params = dopt.apply(state["params"], delta)
         new_state = {"params": new_params,
                      "opt": {"inner": inner,
                              "step": state["opt"]["step"] + rpol.local_steps},
@@ -442,7 +454,8 @@ def build_runtime(model: Model, mesh: jax.sharding.Mesh, rpol: RunPolicy,
             deltas, inner, metrics = local_deltas(
                 state["params"], state["opt"], batch)
             local = lane_mean(deltas, ccfg.acc)
-            new_params = dopt.apply(state["params"], local)
+            with jax.named_scope("optimizer"):
+                new_params = dopt.apply(state["params"], local)
             new_state = {"params": new_params,
                          "opt": {"inner": inner,
                                  "step": state["opt"]["step"]
@@ -464,7 +477,8 @@ def build_runtime(model: Model, mesh: jax.sharding.Mesh, rpol: RunPolicy,
             same trace signature, no cond (the retrace pass pins this)."""
             corr = correction_fn(state["pending"])
             new_state, metrics = local_only_step(state, batch)
-            new_state["params"] = dopt.apply(new_state["params"], corr)
+            with jax.named_scope("optimizer"):
+                new_state["params"] = dopt.apply(new_state["params"], corr)
             return new_state, metrics
 
     if delayed:

@@ -41,6 +41,15 @@ vLLM-style (recompute on re-admission — bitwise-identical continuation,
 though a preempted request restarts on the CURRENT param version).
 Greedy tokens are bitwise-identical to `kv_layout='dense'`; page churn
 never changes a device shape, so the no-retrace contract holds.
+
+Tick phases: `step()` runs inside a `repro:serve.step` host span
+(`repro.runtime.spans`), and its phases inside `serve.admit` (queue
+admission, prefill, first tokens), `serve.grow` (page growth and table
+sync), `serve.decode` (the decode dispatch), `serve.pull` (the next
+tokens to the host) and `serve.commit` (streaming and retirement). A
+profiler trace shows them on the device's clock; `stats["tick_phase_s"]`
+sums each phase's seconds over all ticks, and `stats["slowest_tick"]`
+keeps the longest tick's total and split.
 """
 from __future__ import annotations
 
@@ -50,6 +59,8 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.runtime.spans import span
 
 from .reload import HotReloader
 from .scheduler import (ContinuousBatchingScheduler, GenerationRequest,
@@ -568,6 +579,7 @@ class ServeEngine:
                       "drained": 0, "restore_fallbacks": 0,
                       "degradation_level": 0, "degradation_changes": 0,
                       "ladder_preempts": 0,
+                      "tick_phase_s": {}, "slowest_tick": None,
                       "started_at": None}
         if not self.paged:
             # dense slots pay full capacity up front — that constant IS
@@ -655,6 +667,22 @@ class ServeEngine:
         unshared tail) -> one batched decode over the active slots (paged
         growth/COW first) -> retire finished. Returns True while queued
         or in-flight work remains."""
+        tick: Dict[str, float] = {}
+        with span("serve.step", tick):
+            more = self._tick(tick)
+        self._record_tick(tick)
+        return more
+
+    def _record_tick(self, tick: Dict[str, float]):
+        total = tick.pop("serve.step")
+        phases = self.stats["tick_phase_s"]
+        for name, seconds in tick.items():
+            phases[name] = phases.get(name, 0.0) + seconds
+        slowest = self.stats["slowest_tick"]
+        if slowest is None or total > slowest["total_s"]:
+            self.stats["slowest_tick"] = {"total_s": total, "phases": tick}
+
+    def _tick(self, tick: Dict[str, float]) -> bool:
         self._enforce_deadlines()
         if self._reloader is not None:
             got = self._reloader.poll()
@@ -677,10 +705,11 @@ class ServeEngine:
         # pausing then would deadlock the queue against a full pool)
         blocked = self._draining or (level >= 2 and self.scheduler.active)
         if not blocked:
-            admitted = self.scheduler.admit(
-                self._reserve_pages if self.paged else None)
-            if admitted:
-                self._admit_batch(admitted)
+            with span("serve.admit", tick):
+                admitted = self.scheduler.admit(
+                    self._reserve_pages if self.paged else None)
+                if admitted:
+                    self._admit_batch(admitted)
         if (level >= 3 and self.paged and self._pool.pages_free == 0
                 and len(self.scheduler.active) > 1):
             # preempt-by-recompute rung: free the youngest slot's pages
@@ -688,7 +717,7 @@ class ServeEngine:
             if self._preempt_youngest(None):
                 self.stats["ladder_preempts"] += 1
         if self.scheduler.active:
-            self._decode_tick()
+            self._decode_tick(tick)
         if self._draining and not self.scheduler.active:
             # active set drained: queued requests end terminally (never
             # hung) with finish_reason 'drained'
@@ -1103,7 +1132,7 @@ class ServeEngine:
                     self._pool.ref([old_pid])
                 self._tables_dirty = True
 
-    def _spec_tick(self) -> bool:
+    def _spec_tick(self, tick: Dict[str, float]) -> bool:
         """One speculation tick: draft proposes k tokens per slot (one
         scanned dispatch over its dense cache, healing last tick's
         overrun via the pos rewrite), the target scores all k+1
@@ -1117,8 +1146,9 @@ class ServeEngine:
         k = self.spec_k
         undo: Dict[int, list] = {}
         if self.paged:
-            undo = self._grow_spec(k)       # may preempt under pressure
-            self._sync_tables()
+            with span("serve.grow", tick):
+                undo = self._grow_spec(k)   # may preempt under pressure
+                self._sync_tables()
         active = dict(self.scheduler.active)
         if not active:                      # growth preempted everything
             return True
@@ -1128,73 +1158,92 @@ class ServeEngine:
             # _can_speculate); equals _host_pos for the paged layout
             p_vec[slot] = len(h.request.prompt) + len(h.tokens) - 1
         version = next(iter({h.version for h in active.values()}))
-        toks = jnp.asarray(self._tokens)
-        drafts, self._draft_cache = self._propose(
-            self._draft_params, toks, self._draft_cache,
-            jnp.asarray(p_vec))
-        spec_toks = jnp.concatenate([toks, drafts], axis=1)   # [B, k+1]
-        nxt, g, acc, self.cache = self._verify(
-            self._params[version], spec_toks, self.cache)
+        with span("serve.decode", tick):
+            toks = jnp.asarray(self._tokens)
+            drafts, self._draft_cache = self._propose(
+                self._draft_params, toks, self._draft_cache,
+                jnp.asarray(p_vec))
+            spec_toks = jnp.concatenate([toks, drafts], axis=1)  # [B, k+1]
+            nxt, g, acc, self.cache = self._verify(
+                self._params[version], spec_toks, self.cache)
         del nxt   # == g[b, acc[b]]; _commit feeds _tokens from g anyway
-        g = np.asarray(g)
-        acc_np = np.asarray(acc)
+        with span("serve.pull", tick):
+            g = np.asarray(g)
+            acc_np = np.asarray(acc)
         self.stats["decode_steps"] += 1     # ONE target dispatch
         self.stats["spec_ticks"] += 1
-        for slot, handle in active.items():
-            a = int(acc_np[slot])
-            self.stats["spec_tokens_proposed"] += k
-            self.stats["spec_tokens_accepted"] += a
-            handle.spec_proposed += k
-            handle.spec_accepted += a
-            if self.paged:
-                self._host_pos[slot] += a + 1
-            for t in range(a + 1):
-                self._commit(handle, int(g[slot, t]))
-                if handle.done:
-                    # EOS/budget inside the accepted run: later tokens
-                    # are discarded; the slot's pages are already
-                    # released wholesale, no rollback needed
-                    break
-            if self.paged and not handle.done:
-                self._rollback_spec(undo.get(slot, ()), slot,
-                                    int(p_vec[slot]) + a)
+        with span("serve.commit", tick):
+            for slot, handle in active.items():
+                a = int(acc_np[slot])
+                self.stats["spec_tokens_proposed"] += k
+                self.stats["spec_tokens_accepted"] += a
+                handle.spec_proposed += k
+                handle.spec_accepted += a
+                if self.paged:
+                    self._host_pos[slot] += a + 1
+                for t in range(a + 1):
+                    self._commit(handle, int(g[slot, t]))
+                    if handle.done:
+                        # EOS/budget inside the accepted run: later
+                        # tokens are discarded; the slot's pages are
+                        # already released wholesale, no rollback needed
+                        break
+                if self.paged and not handle.done:
+                    self._rollback_spec(undo.get(slot, ()), slot,
+                                        int(p_vec[slot]) + a)
         return True
 
-    def _decode_tick(self):
-        if self.spec_k and self._spec_tick():
+    def _decode_tick(self, tick: Dict[str, float]):
+        if self.spec_k and self._spec_tick(tick):
             return
         if self.paged:
             # every active slot must own its write page before the batch
             # advances (growth / COW; may preempt under pool pressure)
-            self._grow_active()
-            self._sync_tables()
+            with span("serve.grow", tick):
+                self._grow_active()
+                self._sync_tables()
         active = dict(self.scheduler.active)       # slot -> handle
         if not active:                             # all preempted
             return
         versions = sorted({h.version for h in active.values()})
-        toks = jnp.asarray(self._tokens)
-        # all-greedy ticks take the plain argmax decode (bitwise the
-        # pre-sampling path, no wasted sort/gumbel work); any sampled
-        # slot switches the tick to the sampling step, where greedy rows
-        # still resolve to the identical argmax
-        if any(h.request.temperature > 0 for h in active.values()):
-            policy = (jnp.asarray(self._keys), jnp.asarray(self._pos),
-                      jnp.asarray(self._temp), jnp.asarray(self._topk),
-                      jnp.asarray(self._topp))
-            decode = lambda params: self._decode_sampled(
-                params, toks, self.cache, *policy)
-        else:
-            decode = lambda params: self._decode(params, toks, self.cache)
+        with span("serve.decode", tick):
+            toks = jnp.asarray(self._tokens)
+            # all-greedy ticks take the plain argmax decode (bitwise the
+            # pre-sampling path, no wasted sort/gumbel work); any sampled
+            # slot switches the tick to the sampling step, where greedy
+            # rows still resolve to the identical argmax
+            if any(h.request.temperature > 0 for h in active.values()):
+                policy = (jnp.asarray(self._keys), jnp.asarray(self._pos),
+                          jnp.asarray(self._temp), jnp.asarray(self._topk),
+                          jnp.asarray(self._topp))
+                decode = lambda params: self._decode_sampled(
+                    params, toks, self.cache, *policy)
+            else:
+                decode = lambda params: self._decode(params, toks,
+                                                     self.cache)
+            # a transition tick decodes once per live param version
+            outs = {v: decode(self._params[v]) for v in versions}
+        with span("serve.pull", tick):
+            nxt = self._pull_tokens(outs, versions, active)
+        self.stats["decode_steps"] += 1
+        with span("serve.commit", tick):
+            if self.paged:
+                for slot in active:
+                    self._host_pos[slot] += 1
+            for slot, handle in active.items():
+                self._commit(handle, int(nxt[slot, 0]))
+
+    def _pull_tokens(self, outs, versions, active):
+        """Sets the tick's new cache; returns its tokens on the host."""
         if len(versions) == 1:
-            nxt, self.cache = decode(self._params[versions[0]])
+            nxt, self.cache = outs[versions[0]]
             nxt = np.asarray(nxt)
         else:
-            # transition tick(s): decode once per live version, then keep
-            # each slot's row from the version it is pinned to. Paged:
-            # arena leaves merge by PHYSICAL page ownership (each slot
-            # writes only its own pages; shared prefix pages are
-            # read-only and identical under every version)
-            outs = {v: decode(self._params[v]) for v in versions}
+            # transition tick(s): keep each slot's row from the version
+            # it is pinned to. Paged: arena leaves merge by PHYSICAL page
+            # ownership (each slot writes only its own pages; shared
+            # prefix pages are read-only and identical under every
+            # version)
             merged = outs[versions[0]][1]
             nxt = np.asarray(outs[versions[0]][0]).copy()
             for v in versions[1:]:
@@ -1214,12 +1263,7 @@ class ServeEngine:
                                           merged)
                 nxt[mask] = np.asarray(outs[v][0])[mask]
             self.cache = merged
-        self.stats["decode_steps"] += 1
-        if self.paged:
-            for slot in active:
-                self._host_pos[slot] += 1
-        for slot, handle in active.items():
-            self._commit(handle, int(nxt[slot, 0]))
+        return nxt
 
     def _commit(self, handle: RequestHandle, token: int):
         """Record one generated token; stream it; retire if finished."""
@@ -1275,8 +1319,9 @@ class ServeEngine:
         """Completion/throughput fields (the serve CLI prints these):
         tok/s plus per-request latency — TTFT (submit -> first token)
         and TPOT (per-token cadence after the first), each mean/p50/p99
-        over completed requests — and, under speculation, acceptance
-        accounting and target dispatches per generated token."""
+        over completed requests — the seconds spent in each tick phase,
+        and, under speculation, acceptance accounting and target
+        dispatches per generated token."""
         started = self.stats["started_at"]
         wall = (time.perf_counter() - started) if started else 0.0
         toks = self.stats["generated_tokens"]
@@ -1299,7 +1344,9 @@ class ServeEngine:
                "deadline_kills": self.stats["deadline_kills"],
                "retries": self.stats["retries"],
                "drained": self.stats["drained"],
-               "restore_fallbacks": self.stats["restore_fallbacks"]}
+               "restore_fallbacks": self.stats["restore_fallbacks"],
+               # host seconds per tick phase, summed over every tick
+               "tick_phase_s": dict(self.stats["tick_phase_s"])}
         if self._ladder is not None:
             out["degradation_level"] = self.stats["degradation_level"]
             out["degradation_changes"] = self.stats["degradation_changes"]

@@ -36,6 +36,7 @@ from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.models.api import Model
 from repro.runtime import StepMonitor, FailureInjector
+from repro.runtime.spans import span
 
 from .build import Runtime, build_runtime, make_serve_step
 from .config import EngineConfig
@@ -261,19 +262,25 @@ class TrainSession:
     def step(self, batch: Optional[Dict[str, jnp.ndarray]] = None
              ) -> Dict[str, float]:
         """One optimizer step; advances self.state. With no batch, pulls
-        the deterministic batch for the current step counter."""
-        if batch is None:
-            batch = self.batch(int(jax.device_get(self.state["step"])))
-        if self._delayed_stream is not None:
-            self.state, metrics = self._delayed_stream.step(self.state,
-                                                            batch)
-        else:
-            self.state, metrics = self._step_fn(self.state, batch)
-        out = {k: float(jax.device_get(v)) for k, v in metrics.items()}
-        stats = {k: out[k] for k in STAT_KEYS if k in out}
-        if stats:
-            self._last_stats = stats
-        return out
+        the deterministic batch for the current step counter. Runs in a
+        `repro:train.step` host span, with `train.dispatch` (the step
+        call) and `train.pull` (the metrics to the host) inside it."""
+        with span("train.step"):
+            if batch is None:
+                batch = self.batch(int(jax.device_get(self.state["step"])))
+            with span("train.dispatch"):
+                if self._delayed_stream is not None:
+                    self.state, metrics = self._delayed_stream.step(
+                        self.state, batch)
+                else:
+                    self.state, metrics = self._step_fn(self.state, batch)
+            with span("train.pull"):
+                out = {k: float(jax.device_get(v))
+                       for k, v in metrics.items()}
+            stats = {k: out[k] for k in STAT_KEYS if k in out}
+            if stats:
+                self._last_stats = stats
+            return out
 
     def fit(self, steps: Optional[int] = None) -> List[Dict[str, float]]:
         """Train to `steps` total (resuming from the latest checkpoint if

@@ -175,6 +175,7 @@ def main(argv=None):
     engine.drain()
 
     tp = engine.throughput()
+    phases = tp.pop("tick_phase_s")
     lat = {k: tp.pop(k) for k in list(tp)
            if k.startswith(("ttft_", "tpot_"))}
     res = {k: tp.pop(k) for k in
@@ -190,6 +191,13 @@ def main(argv=None):
     if lat:
         print("[serve] latency " + " ".join(
             f"{k[:-2]}_ms={v * 1e3:.1f}" for k, v in lat.items()))
+    print("[serve] tick phases " + " ".join(
+        f"{k}_s={v:.3f}" for k, v in phases.items()))
+    slowest = engine.stats["slowest_tick"]
+    if slowest:
+        print(f"[serve] slowest tick total_s={slowest['total_s']:.3f} "
+              + " ".join(f"{k}_s={v:.3f}"
+                         for k, v in slowest["phases"].items()))
     if args.speculation_k:
         kv = engine.kv_stats()
         print(f"[serve] spec k={args.speculation_k} "

@@ -10,6 +10,11 @@ Layers are *stacked* [L, ...] and driven by lax.scan (compile time stays
 O(1 layer) even for 64-layer configs) with jax.checkpoint around the block
 body (activation remat). MoE configs may have a dense prefix
 (first_dense_layers) which scans separately.
+
+Inside a block, the attention, Mamba and MLP calls run under
+`jax.named_scope("attention" | "mamba" | "mlp")`, forward and decode
+alike: the names reach every XLA op's `op_name`, backward and
+rematerialised ops included, so a device trace can be summed by layer.
 """
 from __future__ import annotations
 
@@ -78,26 +83,30 @@ def block_apply(params: PyTree, cfg: ModelConfig, x: jnp.ndarray,
         return x, aux
     h = L.rmsnorm(params["norm1"], x, cfg.norm_eps)
     kv = None
-    if cfg.attn_type == "mla":
-        a = ATT.mla_forward(params["attn"], cfg, h, positions, compute_dtype,
-                            attn_chunk, return_kv=return_kv,
-                            prefix_kv=prefix_kv)
-    else:
-        a = ATT.gqa_forward(params["attn"], cfg, h, positions, compute_dtype,
-                            attn_chunk, use_flash, return_kv=return_kv,
-                            prefix_kv=prefix_kv)
+    with jax.named_scope("attention"):
+        if cfg.attn_type == "mla":
+            a = ATT.mla_forward(params["attn"], cfg, h, positions,
+                                compute_dtype, attn_chunk,
+                                return_kv=return_kv, prefix_kv=prefix_kv)
+        else:
+            a = ATT.gqa_forward(params["attn"], cfg, h, positions,
+                                compute_dtype, attn_chunk, use_flash,
+                                return_kv=return_kv, prefix_kv=prefix_kv)
     if return_kv:
         a, kv = a
     if cfg.family == "hybrid":
         assert not return_kv, "fused kv capture needs an attention family"
-        a = (a + SSM.mamba_forward(params["mamba"], cfg, h, compute_dtype)) * 0.5
+        with jax.named_scope("mamba"):
+            m = SSM.mamba_forward(params["mamba"], cfg, h, compute_dtype)
+        a = (a + m) * 0.5
     x = x + a
     h = L.rmsnorm(params["norm2"], x, cfg.norm_eps)
     if moe_block:
         m, aux = MOE.moe_apply(params["moe"], cfg, h, compute_dtype,
                                moe_shards)
     else:
-        m = L.mlp_apply(params["mlp"], h, cfg.mlp_type, compute_dtype)
+        with jax.named_scope("mlp"):
+            m = L.mlp_apply(params["mlp"], h, cfg.mlp_type, compute_dtype)
     if return_kv:
         return x + m, aux, kv
     return x + m, aux
@@ -431,22 +440,24 @@ def _block_decode(params: PyTree, cfg: ModelConfig, x, cache, moe_block,
         cache = cache._replace(x_chan=h[:, 0])
         return x + c_out, cache, jnp.zeros((), jnp.float32)
     h = L.rmsnorm(params["norm1"], x, cfg.norm_eps)
-    if isinstance(cache["attn"], ATT.PagedKVCache):
-        a, att = ATT.gqa_paged_decode_step(params["attn"], cfg, h,
-                                           cache["attn"], compute_dtype)
-    elif isinstance(cache["attn"], ATT.PagedMLACache):
-        a, att = ATT.mla_paged_decode_step(params["attn"], cfg, h,
-                                           cache["attn"], compute_dtype)
-    elif cfg.attn_type == "mla":
-        a, att = ATT.mla_decode_step(params["attn"], cfg, h, cache["attn"],
-                                     compute_dtype)
-    else:
-        a, att = ATT.gqa_decode_step(params["attn"], cfg, h, cache["attn"],
-                                     compute_dtype)
+    with jax.named_scope("attention"):
+        if isinstance(cache["attn"], ATT.PagedKVCache):
+            a, att = ATT.gqa_paged_decode_step(params["attn"], cfg, h,
+                                               cache["attn"], compute_dtype)
+        elif isinstance(cache["attn"], ATT.PagedMLACache):
+            a, att = ATT.mla_paged_decode_step(params["attn"], cfg, h,
+                                               cache["attn"], compute_dtype)
+        elif cfg.attn_type == "mla":
+            a, att = ATT.mla_decode_step(params["attn"], cfg, h,
+                                         cache["attn"], compute_dtype)
+        else:
+            a, att = ATT.gqa_decode_step(params["attn"], cfg, h,
+                                         cache["attn"], compute_dtype)
     cache = dict(cache, attn=att)
     if cfg.family == "hybrid":
-        m, ms = SSM.mamba_decode_step(params["mamba"], cfg, h, cache["mamba"],
-                                      compute_dtype)
+        with jax.named_scope("mamba"):
+            m, ms = SSM.mamba_decode_step(params["mamba"], cfg, h,
+                                          cache["mamba"], compute_dtype)
         a = (a + m) * 0.5
         cache["mamba"] = ms
     x = x + a
@@ -454,7 +465,8 @@ def _block_decode(params: PyTree, cfg: ModelConfig, x, cache, moe_block,
     if moe_block:
         m, aux = MOE.moe_apply(params["moe"], cfg, h, compute_dtype)
     else:
-        m = L.mlp_apply(params["mlp"], h, cfg.mlp_type, compute_dtype)
+        with jax.named_scope("mlp"):
+            m = L.mlp_apply(params["mlp"], h, cfg.mlp_type, compute_dtype)
         aux = jnp.zeros((), jnp.float32)
     return x + m, cache, aux
 
